@@ -13,13 +13,16 @@ and ``(4, 1)`` — over forced host CPU devices, and emits
 * ``micro/<model>/dp_speedup`` — ``1x`` / ``4x`` wall-clock ratio
   (informational; > 1 only on machines with real parallel hardware).
 
-Runs **standalone** (never imported by ``benchmarks/run.py``): the
-device-forcing ``XLA_FLAGS`` must be set before jax first initializes,
-and the aggregator's process has long since locked its single real CPU
-device.  Instead of returning rows to the aggregator it merges its
-pivoted rows into ``BENCH_dataflow.json`` itself (CI runs it right
-after ``run.py``)::
+Runs **standalone** (never imported by ``benchmarks/run.py``): it needs
+at least four devices, and on the CPU those exist only when
+``XLA_FLAGS`` forces them before jax first initializes, which the
+aggregator's process cannot do.  The caller sets the platform and the
+flag; the script refuses to run on fewer than four devices.  Instead
+of returning rows to the aggregator it merges its pivoted rows into
+``BENCH_dataflow.json`` itself (CI runs it right after ``run.py``)::
 
+    JAX_PLATFORMS=cpu \
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python benchmarks/scaling.py --models dcgan
 """
 
@@ -28,21 +31,14 @@ from __future__ import annotations
 import argparse
 import json
 import math
-import os
 import pathlib
 import sys
 import time
 
-# Must precede the first jax initialization: the host platform device
-# count locks at first init (same constraint as launch/dryrun.py).
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
 
 _ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(_ROOT / "src"))
-
-import jax
 
 DEFAULT_BATCH = 8
 DEFAULT_REPEATS = 30
@@ -68,9 +64,15 @@ def run_scaling(models=("dcgan",), channel_scale=0.25,
     from repro.models.gan import GanConfig, init_gan
     from repro.program import Program
 
+    need = max(m[0] * m[1] for _, m in MESHES if m is not None)
+    if len(jax.devices()) < need:
+        raise SystemExit(
+            f"dp scaling needs {need} devices, found "
+            f"{len(jax.devices())}; on the CPU set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count=8 before running")
     rows = []
     print(f"\n== dp scaling: generator program at global batch {batch} "
-          f"over {len(jax.devices())} forced devices "
+          f"over {len(jax.devices())} {jax.devices()[0].platform} devices "
           f"(channels×{channel_scale}) ==")
     for name in models:
         cfg = GanConfig(name=name, channel_scale=channel_scale)

@@ -1,15 +1,14 @@
-"""Version-compat shims over moving JAX APIs.
+"""The JAX APIs this repo uses that have moved between releases.
 
-The repo pins whatever JAX the image bakes in; a handful of APIs we use
-were renamed across releases.  Every call site goes through this module so
-a version bump is a one-file change:
+Every call site goes through this module, so a JAX upgrade is a
+one-file change.  Written for the installed JAX (0.9):
 
-* ``tpu_compiler_params`` — ``pltpu.CompilerParams`` (new spelling) vs
-  ``pltpu.TPUCompilerParams`` (0.4.x spelling).
-* ``shard_map`` — ``jax.shard_map`` with ``check_vma`` (new) vs
-  ``jax.experimental.shard_map.shard_map`` with ``check_rep`` (0.4.x).
-* ``lower_as_mlir`` — ``pl.lower_as_mlir`` (new) vs cross-platform export
-  lowering (0.4.x), both yielding the Mosaic/TPU MLIR for inspection.
+* ``tpu_compiler_params`` — ``pltpu.CompilerParams``.
+* ``shard_map`` — ``jax.shard_map`` with ``check_vma``.
+* ``lower_as_mlir`` — ``pl.lower_as_mlir``: the Mosaic/TPU MLIR of a
+  kernel call, for inspection from a host without a chip.
+* ``CUSTOM_VJP_CALL`` — the jaxpr primitive name of a
+  ``jax.custom_vjp`` call (``custom_vjp_call_jaxpr`` before 0.7).
 """
 
 from __future__ import annotations
@@ -20,39 +19,25 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["tpu_compiler_params", "shard_map", "lower_as_mlir"]
+__all__ = ["tpu_compiler_params", "shard_map", "lower_as_mlir",
+           "CUSTOM_VJP_CALL"]
+
+CUSTOM_VJP_CALL = "custom_vjp_call"
 
 
 def tpu_compiler_params(*, dimension_semantics: Sequence[str]):
-    """Build Pallas-TPU compiler params on either JAX spelling."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(dimension_semantics=tuple(dimension_semantics))
+    """Pallas-TPU compiler params for a grid's dimension semantics."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` with replication/VMA checking toggled portably.
-
-    The entry point (``jax.shard_map`` vs experimental) and the check
-    kwarg (``check_vma`` vs ``check_rep``) were renamed in *different*
-    releases, so both are probed independently."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check)
+    """``jax.shard_map`` with its replication (VMA) check toggled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def lower_as_mlir(f, *args) -> str:
     """Lower ``f(*args)`` for the real TPU target and return the MLIR text
     (works from a CPU host: the kernel must *lower*, not run)."""
-    if hasattr(pl, "lower_as_mlir"):
-        return str(pl.lower_as_mlir(f, *args))
-    from jax import export
-    return export.export(jax.jit(f),
-                         platforms=("tpu",))(*args).mlir_module()
+    return str(pl.lower_as_mlir(f, *args))

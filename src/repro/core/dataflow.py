@@ -441,9 +441,12 @@ class DataflowPolicy:
     """How to pick an execution path for the unified (t)conv ops.
 
     ``backend``:
-      * ``None`` (heuristic) — Pallas on TPU for 2-D layers, polyphase
-        otherwise (the production default: interpret-mode Pallas is a
-        correctness tool, not a fast path).
+      * ``None`` (heuristic) — polyphase on every platform and rank.
+        The pure-JAX polyphase path compiles on the TPU for every
+        Table-I layer, forward and backward, in f32 and bf16; the
+        Pallas kernel does not yet (strided convs, bf16 tap offsets and
+        3dgan g4's VMEM footprint are refused by the chip's compiler),
+        so it is reached only by pinning it or through ``"pallas"``.
       * ``"auto"`` — measurement-driven: at dispatch time the op consults
         the autotuning :class:`repro.tune.Planner` for a plan keyed on
         (layer geometry, dtype, platform); a hit executes the measured
@@ -524,9 +527,7 @@ class DataflowPolicy:
             # usual rank fallback), not whatever auto would pick
             name = "pallas"
         if name is None:
-            name = "pallas-tpu" if (_on_tpu() and
-                                    pallas_kernel_supported(nd)) \
-                else "polyphase"
+            name = "polyphase"
         elif name == "pallas":
             if pallas_kernel_supported(nd):
                 name = "pallas-tpu" if _on_tpu() else "pallas-interpret"
@@ -576,15 +577,23 @@ _SECOND_ORDER_MSG = (
     "native autodiff, which supports arbitrary-order derivatives.")
 
 
-def _reject_higher_order(x, w) -> None:
-    """Kernel backends have no JVP rule: a JVP tracer reaching one means
-    the custom VJP's single backward pass is itself being differentiated
-    (grad-of-grad) or forward-mode is being applied.  Without this check
-    that surfaces as a bare NotImplementedError from deep inside
-    pallas_call; raise the actionable error instead."""
-    from jax.interpreters import ad
-    if isinstance(x, ad.JVPTracer) or isinstance(w, ad.JVPTracer):
-        raise SecondOrderNotImplemented(_SECOND_ORDER_MSG)
+@functools.partial(jax.custom_jvp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _kernel_first_order(fn, strides, paddings, blocks, epilogue, x, w,
+                        bias):
+    """Run a kernel backend's op.  Kernel backends have no JVP rule, so
+    this gives them one that raises: a JVP reaching the kernel means the
+    custom VJP's single backward pass is itself being differentiated
+    (grad-of-grad) or forward-mode is being applied.  Without it that
+    surfaces as a bare NotImplementedError from deep inside pallas_call;
+    raise the actionable error instead."""
+    return fn(x, w, strides, paddings, blocks=blocks, epilogue=epilogue,
+              bias=bias)
+
+
+@_kernel_first_order.defjvp
+def _kernel_first_order_jvp(fn, strides, paddings, blocks, epilogue,
+                            primals, tangents):
+    raise SecondOrderNotImplemented(_SECOND_ORDER_MSG)
 
 
 def _run(backend: str, transposed: bool, x, w, strides, paddings,
@@ -593,9 +602,9 @@ def _run(backend: str, transposed: bool, x, w, strides, paddings,
     b = _BACKENDS[backend]
     fn = b.tconv if transposed else b.conv
     if backend.startswith("pallas"):
-        _reject_higher_order(x, w)
-        return fn(x, w, strides, paddings, blocks=blocks,
-                  epilogue=None if ep.is_identity else ep, bias=bias)
+        return _kernel_first_order(fn, strides, paddings, blocks,
+                                   None if ep.is_identity else ep, x, w,
+                                   bias)
     if blocks is not None:
         raise ValueError(f"blocks={blocks!r} only applies to the Pallas "
                          f"kernel backends, not {backend!r}")

@@ -277,6 +277,24 @@ def test_policy_resolution():
                        interpret=False).resolve(2)
 
 
+def test_heuristic_resolves_polyphase_on_tpu(monkeypatch):
+    """On a TPU host the heuristic, and so an ``auto`` plan miss, picks
+    polyphase, which the chip's compiler accepts for every Table-I
+    layer; the kernel stays reachable pinned or through ``"pallas"``."""
+    from repro.core import dataflow
+    from repro.tune import Planner
+    monkeypatch.setattr(dataflow, "_on_tpu", lambda: True)
+    for nd in (2, 3):
+        assert DataflowPolicy().resolve(nd) == "polyphase"
+        assert DataflowPolicy(backend="pallas").resolve(nd) == "pallas-tpu"
+        assert DataflowPolicy(backend="pallas-tpu").resolve(nd) == \
+            "pallas-tpu"
+    res = dataflow.resolve_execution(
+        DataflowPolicy(backend="auto"), "conv", (32, 32), (4, 4), (2, 2),
+        (1, 1), 128, 256, batch=64, planner=Planner())
+    assert (res.backend, res.source) == ("polyphase", "heuristic")
+
+
 def test_compile_uops_artifacts_frozen():
     u = compile_uops((4, 4), (4, 4), (2, 2), (1, 1))
     assert not u.n_taps.flags.writeable

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.compat import CUSTOM_VJP_CALL
 from repro.configs.gans import GAN_MODELS
 from repro.core.dataflow import (ACTIVATIONS, DataflowPolicy, Epilogue,
                                  conv, tconv)
@@ -177,7 +178,7 @@ def test_no_out_of_kernel_epilogue_ops(name):
     assert not activationish & set(prims), prims
     assert prims.count("add") == 1, prims            # the projection bias
     assert prims.count("custom_jvp_call") == 1       # the projection relu
-    assert prims.count("custom_vjp_call_jaxpr") == len(g_layers)
+    assert prims.count(CUSTOM_VJP_CALL) == len(g_layers)
 
     img_sp = tuple(d_layers[0].in_spatial)
     img = jnp.zeros((1, *img_sp, d_layers[0].cin))
@@ -185,7 +186,7 @@ def test_no_out_of_kernel_epilogue_ops(name):
         lambda d, img: discriminator_apply(d, img, cfg), d, img)
     assert "add" not in prims, prims
     assert not activationish & set(prims), prims
-    assert prims.count("custom_vjp_call_jaxpr") == len(d_layers)
+    assert prims.count(CUSTOM_VJP_CALL) == len(d_layers)
 
 
 @pytest.mark.parametrize("name", sorted(GAN_MODELS))
