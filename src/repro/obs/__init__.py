@@ -30,8 +30,9 @@ Reading a trace::
     # open out.trace.json in https://ui.perfetto.dev
 
 ``obs.profile(outdir)`` additionally captures the device-side JAX
-profiler trace (``jax.profiler.start_trace``/``stop_trace``) with
-``obs.annotate(name)`` regions.
+profiler trace (``jax.profiler.start_trace``/``stop_trace``); while
+tracing is enabled every span is mirrored into it as a
+``jax.profiler.TraceAnnotation``, on the device trace's clock.
 
 Instrumented subsystems and their metric names are tabulated in the
 README's "Observability" section.
@@ -44,7 +45,7 @@ import os
 from repro.obs.export import (from_trace_events, read_records,
                               summarize, to_trace_events, write_jsonl,
                               write_trace_events)
-from repro.obs.jaxbridge import annotate, profile
+from repro.obs.jaxbridge import profile
 from repro.obs.metrics import (DEFAULT_LATENCY_BOUNDS_US, Counter,
                                Gauge, Histogram, Registry)
 from repro.obs.tracer import (JsonlSink, MemorySink, Span, disable,
@@ -61,7 +62,7 @@ __all__ = [
     "Histogram", "DEFAULT_LATENCY_BOUNDS_US",
     "to_trace_events", "from_trace_events", "read_records",
     "write_jsonl", "write_trace_events", "summarize",
-    "profile", "annotate",
+    "profile",
 ]
 
 

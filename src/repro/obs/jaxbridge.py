@@ -2,14 +2,15 @@
 
 ``obs.profile(outdir)`` wraps ``jax.profiler.start_trace`` /
 ``stop_trace`` around a code region (the resulting TensorBoard/Perfetto
-dump shows the *device*-side timeline the host-side obs spans can't
-see), and emits a matching ``obs.profile`` span so the two traces can
-be aligned.  ``obs.annotate(name)`` returns a
-``jax.profiler.TraceAnnotation`` naming a region inside the XLA trace.
+dump shows the *device*-side timeline) inside an ``obs.profile`` span.
+While obs tracing is enabled, every obs span (this one included) is
+also a ``jax.profiler.TraceAnnotation`` of its name, so the program's
+spans appear in the profile's host plane on the device trace's clock,
+beside the device ops they dispatched.
 
-Both degrade to host-side-only behavior when the profiler is
+It degrades to host-side-only behavior when the profiler is
 unavailable (no jax, or a backend without profiling support): the obs
-span still records, the device trace is skipped with a warning attr —
+span still records, the device trace is skipped with an error attr —
 observability must never take the workload down.
 """
 
@@ -19,14 +20,15 @@ import contextlib
 
 from repro.obs import tracer as _tracer
 
-__all__ = ["profile", "annotate"]
+__all__ = ["profile"]
 
 
 @contextlib.contextmanager
 def profile(outdir):
     """Context manager: capture a JAX profiler trace of the region into
-    ``outdir`` (viewable in TensorBoard / Perfetto), plus an
-    ``obs.profile`` span on the obs timeline."""
+    ``outdir`` (viewable in TensorBoard / Perfetto) inside an
+    ``obs.profile`` span; with obs enabled, the obs spans opened in the
+    region are in the profile's host plane too."""
     started = False
     err = None
     try:
@@ -46,14 +48,3 @@ def profile(outdir):
             if started:
                 import jax
                 jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """A named region on the device-side profiler timeline
-    (``jax.profiler.TraceAnnotation``); a no-op context manager when
-    the profiler is unavailable."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()

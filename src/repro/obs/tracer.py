@@ -10,6 +10,12 @@ absent (pinned in tests), and a span opened while a function is being
 ``jit``-traced measures *trace time* exactly once; it can never fire
 inside the compiled computation.
 
+While tracing is enabled and JAX is already imported, each span also
+enters a ``jax.profiler.TraceAnnotation`` of its own name, so that under
+a profiler session (``obs.profile``, ``jax.profiler.start_trace``) it
+lands in the profiler's host plane, on the device trace's clock.  The
+tracer never imports JAX itself.
+
 Enable with :func:`enable` (``sink=None`` → in-memory,
 ``sink="path.jsonl"`` → JSONL file, or any object with
 ``write_record``/``flush``), or via the environment:
@@ -39,6 +45,7 @@ import atexit
 import functools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Callable
@@ -68,6 +75,17 @@ def is_enabled() -> bool:
     """The module-level enabled flag — check this before formatting
     span attributes on a hot path."""
     return _enabled
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` of ``name``, or None
+    when JAX has not been imported (the tracer never imports it)."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
 
 
 def _stack() -> list:
@@ -153,7 +171,8 @@ class Span:
     or a decorator.  When tracing is disabled at ``__enter__`` time the
     span is inert: no clock read, no stack push, no sink write."""
 
-    __slots__ = ("name", "attrs", "_t0_us", "_depth", "_active")
+    __slots__ = ("name", "attrs", "_t0_us", "_depth", "_active",
+                 "_mirror")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -173,6 +192,7 @@ class Span:
         stack = _stack()
         self._depth = len(stack)
         stack.append(self)
+        self._mirror = _annotation(self.name)
         self._t0_us = _now_us()
         return self
 
@@ -181,6 +201,9 @@ class Span:
             return
         t1 = _now_us()
         self._active = False
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+            self._mirror = None
         stack = _stack()
         # tolerate exits out of order (generator-based callers): pop
         # through to this span
@@ -232,7 +255,8 @@ def emit_span(name: str, start_us: float, end_us: float | None = None,
     the scheduler.  ``emit_span`` takes explicit boundaries instead
     (``start_us`` from :func:`now_us`; ``end_us`` defaults to now) and
     writes the span at depth 0 on the emitting thread.  No-op when
-    disabled."""
+    disabled.  Written after the fact, such a span is not mirrored into
+    the profiler's host plane as :func:`trace` spans are."""
     if not _enabled:
         return
     if end_us is None:

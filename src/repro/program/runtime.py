@@ -203,30 +203,32 @@ class Program:
         contracts with an f32 accumulator (``preferred_element_type``,
         matching the conv backends' f32 scratch), and biases stay f32
         into the fused epilogues.  Bit-identical to the historic path
-        for f32 specs."""
+        for f32 specs.
+
+        Each layer's work runs under ``jax.named_scope("layer.<name>")``
+        (``layer.proj`` for the generator's projection, ``layer.head``
+        for the discriminator's mean), so every op it compiles to
+        carries the layer's name in its HLO ``op_name`` and in the
+        device trace, whichever backend the spec froze."""
         spec = self.spec
         sd = self._storage
         sharded = self.mesh is not None
         x = x.astype(sd)
         if spec.role == "generator":
             first = spec.layers[0]
-            x = jnp.dot(x, params["proj_w"].astype(sd),
-                        preferred_element_type=jnp.float32)
-            x = x + params["proj_b"].astype(jnp.float32)
-            x = x.reshape((x.shape[0],) + first.in_spatial
-                          + (first.cin,))
-            x = jax.nn.relu(x).astype(sd)
+            with jax.named_scope("layer.proj"):
+                x = jnp.dot(x, params["proj_w"].astype(sd),
+                            preferred_element_type=jnp.float32)
+                x = x + params["proj_b"].astype(jnp.float32)
+                x = x.reshape((x.shape[0],) + first.in_spatial
+                              + (first.cin,))
+                x = jax.nn.relu(x).astype(sd)
         batch = x.shape[0]
         for le, policy in zip(spec.layers, self._policies):
-            w = params[le.w_param].astype(sd)
-            b = params[le.b_param] if le.bias else None
             op = df_tconv if le.kind == "tconv" else df_conv
-            # Host-side span: under jit this records *trace* time (how
-            # long building this layer's computation took), exactly once
-            # per executable — it never enters the jaxpr.
-            with _obs.trace("program.layer", layer=le.name, kind=le.kind,
-                            backend=le.backend, source=le.source,
-                            measured_us=le.measured_us):
+            with jax.named_scope(f"layer.{le.name}"):
+                w = params[le.w_param].astype(sd)
+                b = params[le.b_param] if le.bias else None
                 x = op(x, w, le.strides, le.paddings, policy=policy,
                        blocks=le.blocks, bias=b, epilogue=le.epilogue)
                 if sharded and le.sharding == "cout":
@@ -240,7 +242,8 @@ class Program:
             # logits reduce in f32 (a bf16 mean over every pixel would
             # lose the signal) and *stay* f32 — losses are always
             # computed at full precision
-            x = x.reshape(batch, -1).mean(axis=-1, dtype=jnp.float32)
+            with jax.named_scope("layer.head"):
+                x = x.reshape(batch, -1).mean(axis=-1, dtype=jnp.float32)
         return x
 
     def apply(self, params, x):

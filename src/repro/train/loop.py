@@ -68,7 +68,12 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
     optimizer state, and gradients stay f32 end to end — the
     ``state_shardings`` f32 shape-structs and checkpoints need no
     change, and the step stays numerically stable at low storage
-    precision."""
+    precision.
+
+    The step names its parts for the device trace: D's and G's
+    ``value_and_grad`` run under ``jax.named_scope("gan.d_update")`` and
+    ``"gan.g_update"``, the SGD updates under ``"gan.sgd"``, and each
+    layer inside them under the programs' ``layer.<name>`` scopes."""
     from repro.models.gan import bce_with_logits
     from repro.program import Program
 
@@ -91,14 +96,18 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
     def train_step(state, batch):
         g_params, d_params = state
         z, real = batch["z"], batch["real"]
-        dl, d_grads = jax.value_and_grad(
-            lambda d: losses(g_params, d, z, real)[1])(d_params)
-        d_new = jax.tree.map(lambda p, g: p - d_lr * g, d_params,
-                             d_grads)
-        gl, g_grads = jax.value_and_grad(
-            lambda g: losses(g, d_new, z, real)[0])(g_params)
-        g_new = jax.tree.map(lambda p, g: p - g_lr * g, g_params,
-                             g_grads)
+        with jax.named_scope("gan.d_update"):
+            dl, d_grads = jax.value_and_grad(
+                lambda d: losses(g_params, d, z, real)[1])(d_params)
+        with jax.named_scope("gan.sgd"):
+            d_new = jax.tree.map(lambda p, g: p - d_lr * g, d_params,
+                                 d_grads)
+        with jax.named_scope("gan.g_update"):
+            gl, g_grads = jax.value_and_grad(
+                lambda g: losses(g, d_new, z, real)[0])(g_params)
+        with jax.named_scope("gan.sgd"):
+            g_new = jax.tree.map(lambda p, g: p - g_lr * g, g_params,
+                                 g_grads)
         return (g_new, d_new), {"g_loss": gl, "d_loss": dl,
                                 "loss": gl + dl}
 

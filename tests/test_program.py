@@ -9,6 +9,7 @@ files degrading to fresh resolution.
 
 import json
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -216,6 +217,35 @@ def test_make_gan_train_step_builds_programs_once():
     assert np.isfinite(float(metrics["loss"]))
     # the step embeds the programs' forward, not their jitted apply
     assert g_prog.traces == 0
+
+
+@pytest.mark.parametrize("name", ["dcgan", "3dgan"])
+def test_train_step_names_every_layer_in_the_compiled_hlo(name):
+    """Each ``LayerExec``'s ops carry its ``layer.<name>`` scope in the
+    compiled step's ``op_name`` metadata, forward and backward, and D's
+    layers run under both update phases."""
+    from repro.train.loop import make_gan_train_step
+    cfg = GanConfig(name=name, channel_scale=0.03125)
+    step, (g_prog, d_prog) = make_gan_train_step(cfg, 2, g_lr=1e-3)
+    state = init_gan(cfg, jax.random.PRNGKey(0))
+    image = d_prog.spec.layers[0]
+    batch = {"z": jax.ShapeDtypeStruct((2, cfg.z_dim), jnp.float32),
+             "real": jax.ShapeDtypeStruct(
+                 (2, *image.in_spatial, image.cin), jnp.float32)}
+    text = step.lower(state, batch).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    names = ["proj"] + [le.name for le in g_prog.spec.layers
+                        + d_prog.spec.layers]
+    for layer in names:
+        assert any(f"jvp(layer.{layer})" in p for p in paths), layer
+        assert any(f"transpose(jvp(layer.{layer}))" in p
+                   for p in paths), layer
+    for le in d_prog.spec.layers:
+        for phase in ("gan.d_update/", "gan.g_update/"):
+            assert any(p.startswith(f"jit(train_step)/{phase}")
+                       and f"(layer.{le.name})" in p for p in paths), \
+                (phase, le.name)
+    assert any(p.startswith("jit(train_step)/gan.sgd/") for p in paths)
 
 
 # ---------------------------------------------------------------------------
