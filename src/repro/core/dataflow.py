@@ -31,7 +31,7 @@ repo.  It owns three things:
    forward and backward passes.  The pure-JAX backends keep XLA's
    native autodiff, which is already fused (and, for polyphase,
    already zero-skipping — the backward of a phase conv is a phase
-   conv).
+   conv, that of the packed form two stride-1 convs).
 
 4. **Fused epilogue** — an :class:`Epilogue` (bias add + activation)
    is a first-class argument of :func:`tconv` / :func:`conv`.  On the
@@ -63,7 +63,7 @@ import numpy as np
 
 from repro import obs as _obs
 from repro.core.scheduler import PhaseSchedule, make_schedule
-from repro.core.tconv import tconv_ganax, tconv_zero_insert
+from repro.core.tconv import tconv_ganax, tconv_taps, tconv_zero_insert
 
 __all__ = [
     "Backend",
@@ -400,10 +400,27 @@ def _conv_dense(x, w, strides, paddings):
     return conv_ref(x, w, strides, paddings)
 
 
+# The MXU's and the vector unit's lane width.
+_LANES = 128
+
+
 def _tconv_polyphase(x, w, strides, paddings):
     nd = x.ndim - 2
     u = compile_uops(x.shape[1:1 + nd], w.shape[:nd], tuple(strides),
                      tuple(paddings))
+    # The phase convs read all of x once per phase, and with few output
+    # channels each makes few MACs per element of x it reads, so they
+    # are bound by those reads; the packed form reads x once.  Taken for
+    # a lane-sparse output (even its phases packed into channels fill no
+    # lane tile) with 8 phases or more: a 3-D stride-2 output layer.  A
+    # stride-1 layer has one phase and nothing to save.  A 2-D stride-2
+    # output layer (4 phases) gains too, but keeps the phase convs until
+    # the benchmark's least-bytes count can measure it (PERF.md, Open
+    # question 9).
+    phases = int(np.prod(strides))
+    if phases >= 8 and w.shape[-1] * phases < _LANES:
+        _obs.counter("dataflow.tconv.taps").inc()
+        return tconv_taps(x, w, strides, paddings, schedule=u.schedule)
     return tconv_ganax(x, w, strides, paddings, schedule=u.schedule)
 
 

@@ -1,6 +1,6 @@
 """GANAX transposed convolution: polyphase ("row-reorganized") dataflow.
 
-Two executable dataflows are provided for N-D transposed convolution
+Three executable forms are provided for N-D transposed convolution
 (channels-last layout, PyTorch ``ConvTranspose`` geometry semantics):
 
 * :func:`tconv_zero_insert` — the conventional-accelerator baseline the
@@ -14,7 +14,19 @@ Two executable dataflows are provided for N-D transposed convolution
   being a dense, fully-regular convolution (SIMD inside a phase, distinct
   microprograms across phases = MIMD-SIMD).
 
-Both produce bit-comparable results (up to dtype accumulation order) and
+* :func:`tconv_taps` — every phase in one convolution: taps written
+  ``t = s·j + r`` (phase ``r``, shift ``j``), one stride-1 conv over a
+  window of ``ceil(k / s)`` shifts with the phases packed into its
+  ``prod(s)·Cout`` output channels, then the phases interleaved.  Still
+  GANAX zero-skipping, the same consequential MACs grouped into one
+  conv instead of one per phase (a kernel whose size is not a multiple
+  of the stride is padded with zero taps; no zoo layer has one).  It
+  reads x once where the phase convs read it once per phase, which is
+  what bounds a phase conv with few output channels.  The polyphase
+  backend (``core/dataflow.py``) takes it for a lane-sparse output
+  layer with 8 phases or more (3dgan g4).
+
+All produce bit-comparable results (up to dtype accumulation order) and
 match ``jax.lax.conv_transpose``.
 """
 
@@ -33,6 +45,7 @@ from repro.core.scheduler import PhaseSchedule, make_schedule
 __all__ = [
     "tconv_zero_insert",
     "tconv_ganax",
+    "tconv_taps",
     "tconv_output_shape",
     "interleave_phases",
 ]
@@ -225,3 +238,56 @@ def tconv_ganax(x: jax.Array, w: jax.Array, strides: Sequence[int],
     if sched.n_phases == 1:
         return outs[(0,) * nd]
     return interleave_phases(outs, sched)
+
+
+def tconv_taps(x: jax.Array, w: jax.Array, strides: Sequence[int],
+               paddings: Sequence[int],
+               preferred_element_type=jnp.float32,
+               schedule: PhaseSchedule | None = None) -> jax.Array:
+    """Transposed conv via the GANAX dataflow with every phase in one conv.
+
+    Write each kernel tap as ``t = s·j + r``: ``r`` is the output phase
+    the tap lands on, ``j`` its shift within that phase.  One stride-1
+    convolution over a window of ``J = ceil(k / s)`` shifts, with the
+    phases packed into its ``prod(s)·Cout`` output channels, computes
+    ``Z[n, u, (r, o)] = Σ_{j, c} x[n, u − j, c] · w[s·j + r, c, o]`` for
+    every phase at once (x read once, not once per phase); the phases
+    are then interleaved (``s·u + r``) and the padding cropped.  The
+    same MACs as :func:`tconv_ganax` where ``kernel % stride == 0``;
+    otherwise the kernel is padded with zero taps up to ``J·s``.
+    """
+    nd = _spatial_dims(x)
+    strides = tuple(strides)
+    sched = schedule or make_schedule(x.shape[1:1 + nd], w.shape[:nd],
+                                      strides, tuple(paddings))
+    n, in_sp, (cin, cout) = x.shape[0], x.shape[1:1 + nd], w.shape[-2:]
+    shifts = tuple(-(-k // s) for k, s in zip(w.shape[:nd], strides))
+    # (*k, Cin, Cout) → (J_0, s_0, J_1, s_1, …, Cin, Cout) → (J…, Cin,
+    # (s…, Cout)), flipped over J: the conv correlates
+    w = jnp.pad(w, [(0, j * s - k) for j, s, k in
+                    zip(shifts, strides, w.shape[:nd])] + [(0, 0)] * 2)
+    w = w.reshape(sum(zip(shifts, strides), ()) + (cin, cout))
+    w = jnp.transpose(w, (*range(0, 2 * nd, 2), 2 * nd,
+                          *range(1, 2 * nd, 2), 2 * nd + 1))
+    w = jnp.flip(w.reshape(shifts + (cin, int(np.prod(strides)) * cout)),
+                 tuple(range(nd)))
+    with jax.named_scope("tconv.taps"):
+        z = accum_conv(x, w, window_strides=(1,) * nd,
+                       padding=tuple((j - 1, j - 1) for j in shifts),
+                       dimension_numbers=_dim_numbers(nd),
+                       preferred_element_type=preferred_element_type)
+    with jax.named_scope("tconv.interleave"):
+        # (N, U…, s…, Cout) → (N, U_0, s_0, U_1, s_1, …, Cout): output
+        # position s·u + r of the uncropped transposed conv
+        u_sp = tuple(i + j - 1 for i, j in zip(in_sp, shifts))
+        z = z.reshape((n, *u_sp, *strides, cout))
+        z = jnp.transpose(z, (0, *(a for d in range(nd)
+                                   for a in (1 + d, 1 + nd + d)),
+                              1 + 2 * nd))
+        z = z.reshape((n, *(u * s for u, s in zip(u_sp, strides)), cout))
+        out = lax.pad(z, jnp.zeros((), z.dtype),
+                      [(0, 0, 0), *((-p, o + p - u * s, 0) for p, o, u, s
+                                    in zip(paddings, sched.out_sizes,
+                                           u_sp, strides)),
+                       (0, 0, 0)])
+    return out.astype(x.dtype)

@@ -13,8 +13,11 @@ only once one of its tests has started.
   compile; the three known refusals are strict xfails, so the kernel
   fix that lifts one flips its case.
 * The default resolution (``DataflowPolicy()``, polyphase) compiles
-  every layer of dcgan, forward and backward.
+  every layer of dcgan, and 3dgan g4 in the packed form, forward and
+  backward.
 """
+
+import re
 
 import pytest
 import jax
@@ -96,12 +99,30 @@ def test_pinned_kernel_compiles_for_v5e(one_chip, model, name, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("name", [l.name for role in GAN_MODELS["dcgan"]
-                                  for l in role])
-def test_default_resolution_compiles_for_v5e(one_chip, name):
+def _conv_output_features(hlo: str) -> list[int]:
+    """The output feature size of every convolution in an optimized HLO
+    module (the dimension ``f`` of its ``dim_labels`` output)."""
+    feats = []
+    for shape, labels in re.findall(
+            r"= \w+\[([\d,]*)\][^ ]* convolution\(.*?"
+            r"dim_labels=[^_]+_[^-]+->(\w+)", hlo):
+        feats.append(int(shape.split(",")[labels.index("f")]))
+    return feats
+
+
+_DEFAULT_LAYERS = [("dcgan", l.name) for role in GAN_MODELS["dcgan"]
+                   for l in role] + [("3dgan", "g4")]
+
+
+@pytest.mark.parametrize(
+    "model,name", _DEFAULT_LAYERS,
+    ids=[n if m == "dcgan" else f"{m}-{n}" for m, n in _DEFAULT_LAYERS])
+def test_default_resolution_compiles_for_v5e(one_chip, model, name):
     """``DataflowPolicy()`` resolves to a backend the chip's compiler
-    accepts, for the forward pass and both cotangents."""
-    layer = _layer("dcgan", name)
+    accepts, for the forward pass and both cotangents.  3dgan g4 (Cout
+    1) runs in the packed form: no convolution of its module, forward or
+    backward, leaves the MXU a single output column."""
+    layer = _layer(model, name)
     f = _forward(layer, DataflowPolicy())
 
     def loss(x, w):
@@ -109,4 +130,8 @@ def test_default_resolution_compiles_for_v5e(one_chip, name):
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         *_operands(layer, jnp.float32, one_chip)).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    if model == "3dgan":
+        feats = _conv_output_features(hlo)
+        assert feats and 1 not in feats, feats

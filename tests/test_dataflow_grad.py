@@ -13,10 +13,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs as _obs
 from repro.core.dataflow import (DataflowPolicy, SecondOrderNotImplemented,
                                  compile_uops, conv, tconv,
                                  uop_cache_clear, uop_cache_info)
-from repro.core.tconv import tconv_zero_insert
+from repro.core.tconv import tconv_taps, tconv_zero_insert
 from repro.kernels.ref import conv_ref
 
 BACKENDS = ["zero-insert", "polyphase", "pallas-interpret", "pallas"]
@@ -145,6 +146,77 @@ def test_conv_grad_parity_3d(backend, xs, ws, s, p):
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(gw), np.asarray(gw_ref),
                                atol=1e-4, rtol=1e-4)
+
+
+# Lane-sparse outputs (Cout 1 and 3): the packed form, every phase in one
+# stride-1 conv with the phases in its output channels.
+TAPS_CASES = [
+    ((2, 5, 7, 4), (4, 4, 4, 1), (2, 2), (1, 1)),
+    ((1, 5, 3, 3), (5, 3, 3, 3), (3, 2), (0, 2)),
+    ((1, 3, 4, 2), (2, 2, 2, 3), (3, 3), (1, 0)),      # kernel < stride
+    ((2, 5, 5, 3), (5, 5, 3, 3), (1, 1), (4, 4)),
+    ((1, 3, 5, 4, 2), (4, 4, 4, 2, 1), (2, 2, 2), (1, 1, 1)),
+    ((1, 3, 2, 3, 3), (3, 2, 5, 3, 3), (2, 3, 1), (2, 1, 0)),
+]
+
+
+def _taps_count() -> int:
+    return _obs.snapshot()["counters"].get("dataflow.tconv.taps", 0)
+
+
+@pytest.mark.parametrize("xs,ws,s,p", TAPS_CASES)
+def test_tconv_taps_grad_parity(xs, ws, s, p):
+    """dx, dw and db of the packed form (native autodiff through the
+    conv and the interleave, tanh + bias epilogue) match autodiff of the
+    zero-insertion oracle."""
+    rng = np.random.default_rng(hash((xs, ws, s, p)) % 2**31)
+    x = jnp.asarray(rng.normal(size=xs), jnp.float32)
+    w = jnp.asarray(rng.normal(size=ws), jnp.float32)
+    b = jnp.asarray(rng.normal(size=ws[-1:]), jnp.float32)
+    cot = jnp.asarray(rng.normal(size=tconv_zero_insert(x, w, s, p).shape),
+                      jnp.float32)
+
+    def grads(fn):
+        return jax.grad(lambda x, w, b: jnp.sum(
+            jnp.tanh(fn(x, w, s, p) + b) * cot), argnums=(0, 1, 2))(x, w, b)
+
+    got = grads(tconv_taps)
+    ref = grads(tconv_zero_insert)
+    for g, r, name in zip(got, ref, ("dx", "dw", "db")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+# The zoo's tconvs that take the packed form: a lane-sparse output layer
+# with 8 phases or more.  Every other layer keeps the phase convs: the
+# strided ones with Cout >= 64, the 2-D output layers (4 phases) and the
+# stride-1 ones (one phase, so no x read per phase to save).
+TAPS_LAYERS = {("3dgan", "g4")}
+
+
+def test_tconv_taps_routing_pins_zoo():
+    """The rule is geometric: across every zoo model's tconv layers at
+    published widths, ``dataflow.tconv.taps`` counts exactly the layers
+    pinned above, and the default resolution routes them so."""
+    from repro.configs.gans import GAN_MODELS
+
+    taken = set()
+    for model, (g_layers, d_layers) in GAN_MODELS.items():
+        for layer in g_layers + d_layers:
+            if not layer.transposed:
+                continue
+            x = jax.ShapeDtypeStruct((1, *layer.in_spatial, layer.cin),
+                                     jnp.float32)
+            w = jax.ShapeDtypeStruct((*layer.kernel, layer.cin, layer.cout),
+                                     jnp.float32)
+            before = _taps_count()
+            jax.eval_shape(lambda x, w: tconv(x, w, layer.strides,
+                                              layer.paddings), x, w)
+            count = _taps_count() - before
+            assert count in (0, 1), (model, layer.name, count)
+            if count:
+                taken.add((model, layer.name))
+    assert taken == TAPS_LAYERS
 
 
 def test_3dgan_layers_pallas_forward_and_vjp_parity():
