@@ -169,7 +169,7 @@ def bench_program(models=("dcgan", "3dgan"), batch=2, channel_scale=0.25,
                         backend=backend)
         g_params, _ = init_gan(cfg, jax.random.PRNGKey(0))
         z = jnp.asarray(np.random.default_rng(0).normal(
-            size=(batch, cfg.z_dim)), jnp.float32)
+            size=(batch, *cfg.input_shape)), jnp.float32)
         prog = Program.build(cfg, batch, "generator")
         legacy = jax.jit(lambda p, z, cfg=cfg: generator_apply(p, z, cfg))
         t_prog = _time(prog.apply, g_params, z, iters=repeats)
@@ -227,7 +227,7 @@ def bench_precision(models=("dcgan", "3dgan"), batch=2,
                           backend=backend, dtype="bfloat16")
         g_params, _ = init_gan(cfg32, jax.random.PRNGKey(0))
         z = jnp.asarray(np.random.default_rng(0).normal(
-            size=(batch, cfg32.z_dim)), jnp.float32)
+            size=(batch, *cfg32.input_shape)), jnp.float32)
 
         prog_bf = Program.build(cfgbf, batch, "generator")
         t_bf = _time(prog_bf.apply, g_params, z, iters=repeats)
@@ -310,7 +310,7 @@ def bench_obs_overhead(models=("dcgan",), batch=2,
                             backend=backend)
             g_params, _ = init_gan(cfg, jax.random.PRNGKey(0))
             z = jnp.asarray(np.random.default_rng(0).normal(
-                size=(batch, cfg.z_dim)), jnp.float32)
+                size=(batch, *cfg.input_shape)), jnp.float32)
             prog = Program.build(cfg, batch, "generator")
             thunks = [lambda: prog.apply(g_params, z),
                       lambda: prog._apply(g_params, z)]

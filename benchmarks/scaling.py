@@ -78,7 +78,7 @@ def run_scaling(models=("dcgan",), channel_scale=0.25,
         cfg = GanConfig(name=name, channel_scale=channel_scale)
         g_params, _ = init_gan(cfg, jax.random.PRNGKey(seed))
         z = jax.random.normal(jax.random.PRNGKey(seed + 1),
-                              (batch, cfg.z_dim))
+                              (batch, *cfg.input_shape))
         times = {}
         for label, mesh in MESHES:
             prog = Program.build(cfg, batch, mesh=mesh,

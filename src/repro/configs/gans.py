@@ -87,7 +87,10 @@ ARTGAN_D = [
 ]
 
 # --------------------------------------------------------------------------
-# DiscoGAN (Kim et al. 2017): encoder-decoder generator (5 conv + 5 tconv).
+# DiscoGAN (Kim et al. 2017): encoder-decoder generator (5 conv + 5 tconv)
+# that maps an image to an image: its first layer is a conv, so the
+# generator takes the image itself (``GanConfig.input_kind``), with no
+# z-projection, and trains with the cross-domain step (train/loop.py).
 # --------------------------------------------------------------------------
 DISCOGAN_G = [
     _c("e1", 64, 4, 2, 1, 3, 64),

@@ -98,6 +98,23 @@ class GanConfig:
                                           use_pallas=self.use_pallas)
 
     @property
+    def input_kind(self) -> str:
+        """What the generator takes, fixed by the model: ``"latent"`` (z
+        of ``z_dim``, projected onto the first tconv's input) or
+        ``"image"`` (an encoder-decoder, whose first layer is a conv)."""
+        return "latent" if GAN_MODELS[self.name][0][0].transposed \
+            else "image"
+
+    @property
+    def input_shape(self) -> tuple[int, ...]:
+        """The generator's input per sample: ``(z_dim,)`` or the first
+        layer's ``(*spatial, channels)``."""
+        if self.input_kind == "latent":
+            return (self.z_dim,)
+        first = self.layers[0][0]
+        return tuple(first.in_spatial) + (first.cin,)
+
+    @property
     def layers(self) -> tuple[list[ConvLayer], list[ConvLayer]]:
         g, d = GAN_MODELS[self.name]
         if self.channel_scale != 1.0:
@@ -126,12 +143,15 @@ def _conv_specs(layers: Sequence[ConvLayer], prefix: str) -> dict:
 
 
 def generator_specs(cfg: GanConfig) -> dict:
+    """The z-projection (latent input only) and ``t<i>`` per layer."""
     g_layers, _ = cfg.layers
-    first = g_layers[0]
-    proj_dim = int(jnp.prod(jnp.asarray(first.in_spatial))) * first.cin
-    specs = {"proj_w": PSpec((cfg.z_dim, proj_dim), (None, "mlp"),
-                             scale=0.02),
-             "proj_b": PSpec((proj_dim,), ("mlp",), init="zeros")}
+    specs = {}
+    if cfg.input_kind == "latent":
+        first = g_layers[0]
+        proj_dim = int(jnp.prod(jnp.asarray(first.in_spatial))) * first.cin
+        specs = {"proj_w": PSpec((cfg.z_dim, proj_dim), (None, "mlp"),
+                                 scale=0.02),
+                 "proj_b": PSpec((proj_dim,), ("mlp",), init="zeros")}
     specs.update(_conv_specs(g_layers, "t"))
     return specs
 
@@ -148,12 +168,17 @@ def init_gan(cfg: GanConfig, key: jax.Array):
 
 
 def generator_epilogues(g_layers: Sequence[ConvLayer]) -> list[Epilogue]:
-    """Per-layer fused epilogues of a Table-I generator: bias + ReLU on
-    every hidden layer, bias + tanh on the image-producing last one."""
+    """Per-layer fused epilogues of a Table-I generator: bias + tanh on
+    the image-producing last layer; bias + LeakyReLU on an encoder's
+    convs (DiscoGAN's, as its paper's encoder); bias + ReLU on every
+    other (tconv) layer."""
     last = len(g_layers) - 1
-    return [Epilogue(bias=True,
-                     activation="tanh" if i == last else "relu")
-            for i in range(len(g_layers))]
+    return [Epilogue(bias=True, activation="tanh")
+            if i == last else
+            Epilogue(bias=True, activation="relu") if l.transposed else
+            Epilogue(bias=True, activation="leaky_relu",
+                     leaky_slope=LEAKY_SLOPE)
+            for i, l in enumerate(g_layers)]
 
 
 def discriminator_epilogues(d_layers: Sequence[ConvLayer]
@@ -194,13 +219,15 @@ def _program_for(cfg: GanConfig, policy: DataflowPolicy | None,
 
 def generator_apply(params, z, cfg: GanConfig,
                     policy: DataflowPolicy | None = None):
-    """z (B, z_dim) → image (B, *spatial, C).
+    """z (B, z_dim), or an image for an image-input generator
+    (``cfg.input_kind``), → image (B, *spatial, C).
 
     Legacy-compatible wrapper over a cached ahead-of-time
     :class:`repro.program.Program`: the layer walk (config → policy →
     epilogues → plans) runs once at program build, not per call.  Every
     conv layer's bias+activation runs as a fused epilogue inside the
-    unified op (only the z-projection MLP keeps its own bias/ReLU)."""
+    unified op (only a latent generator's z-projection MLP keeps its own
+    bias/ReLU)."""
     prog = _program_for(cfg, policy, "generator", int(z.shape[0]))
     return prog.forward(params, z)
 

@@ -89,7 +89,8 @@ class Program:
                 _obs.counter("program.sharded").inc()
         # parameter layouts for the sharded path: Cout-sharded layers
         # split their weight's last (Cout) axis and bias over "model";
-        # everything else (incl. the generator projection) replicates
+        # everything else (incl. a latent generator's projection)
+        # replicates
         self._param_pspecs = {}
         if self.mesh is not None:
             for le in spec.layers:
@@ -206,7 +207,8 @@ class Program:
         for f32 specs.
 
         Each layer's work runs under ``jax.named_scope("layer.<name>")``
-        (``layer.proj`` for the generator's projection, ``layer.head``
+        (``layer.proj`` for a latent generator's projection, which an
+        image-input generator does not have, ``layer.head``
         for the discriminator's mean), so every op it compiles to
         carries the layer's name in its HLO ``op_name`` and in the
         device trace, whichever backend the spec froze."""
@@ -214,7 +216,7 @@ class Program:
         sd = self._storage
         sharded = self.mesh is not None
         x = x.astype(sd)
-        if spec.role == "generator":
+        if spec.input_kind == "latent":
             first = spec.layers[0]
             with jax.named_scope("layer.proj"):
                 x = jnp.dot(x, params["proj_w"].astype(sd),

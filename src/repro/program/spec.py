@@ -34,7 +34,7 @@ from repro.core.dataflow import (SHARDINGS, DataflowPolicy, Epilogue,
                                  blocks_valid, resolve_execution)
 
 __all__ = ["LayerExec", "ProgramSpec", "PROGRAM_FORMAT_VERSION",
-           "SUPPORTED_PROGRAM_VERSIONS", "ROLES"]
+           "SUPPORTED_PROGRAM_VERSIONS", "ROLES", "INPUT_KINDS"]
 
 # Version 2 added the mesh/sharding fields; version 3 added the
 # optional embedded int8 weight payload (``quantized_params``, written
@@ -45,6 +45,11 @@ PROGRAM_FORMAT_VERSION = 3
 SUPPORTED_PROGRAM_VERSIONS = (1, 2, 3)
 
 ROLES = ("generator", "discriminator")
+
+# A program's input signature: a latent of ``z_dim`` (projected onto the
+# first layer's input) or an image of the first layer's input shape.  A
+# discriminator always takes an image.
+INPUT_KINDS = ("latent", "image")
 
 # ``build(mesh=...)``'s "not passed" sentinel: None is a meaningful
 # value (force single-device even if cfg carries a mesh).
@@ -223,12 +228,20 @@ class ProgramSpec:
     program (the v3 JSON form; see
     :func:`repro.quant.weights.quantize_program`) — ``None`` for
     ordinary specs, whose params live with the caller.
+
+    ``input_kind`` is the input signature (:data:`INPUT_KINDS`): a
+    ``"latent"`` generator takes ``(B, z_dim)`` through its projection;
+    an ``"image"`` generator (an encoder-decoder) and every
+    discriminator take ``(B, *layers[0].in_spatial, layers[0].cin)``
+    (:attr:`input_shape`).  ``None`` picks the role's historic default
+    (latent generator, image discriminator), which is also how a file
+    written before the field reads.
     """
 
     model: str
     role: str                       # "generator" | "discriminator"
     batch: int
-    z_dim: int | None               # generator programs only
+    z_dim: int | None               # latent generator programs only
     channel_scale: float
     dtype: str
     platform: str
@@ -236,12 +249,25 @@ class ProgramSpec:
     layers: tuple[LayerExec, ...]
     mesh: tuple[int, int] | None = None
     quantized_params: dict | None = None
+    input_kind: str | None = None
 
     def __post_init__(self):
         from repro.quant.precision import canonical_dtype
         if self.role not in ROLES:
             raise ValueError(f"unknown program role {self.role!r}; "
                              f"one of {ROLES}")
+        if self.input_kind is None:
+            object.__setattr__(self, "input_kind",
+                               "latent" if self.role == "generator"
+                               else "image")
+        if self.input_kind not in INPUT_KINDS:
+            raise ValueError(f"unknown program input {self.input_kind!r}; "
+                             f"one of {INPUT_KINDS}")
+        if self.input_kind == "latent" and (self.role != "generator"
+                                            or self.z_dim is None):
+            raise ValueError(f"a latent input needs a generator with a "
+                             f"z_dim (role={self.role!r}, "
+                             f"z_dim={self.z_dim!r})")
         # canonicalize ("bf16" → "bfloat16") and reject non-storage
         # dtypes before they leak into plan keys or serialized files
         object.__setattr__(self, "dtype", canonical_dtype(self.dtype))
@@ -321,10 +347,11 @@ class ProgramSpec:
         else:
             layers, prefix = d_layers, "c"
             epilogues = discriminator_epilogues(d_layers)
+        input_kind = cfg.input_kind if role == "generator" else "image"
         records = []
         with _obs.trace("program.build", model=cfg.name, role=role,
                         batch=int(batch), measure=bool(measure),
-                        layers=len(layers)):
+                        layers=len(layers), input=input_kind):
             for i, (l, ep) in enumerate(zip(layers, epilogues)):
                 kind = "tconv" if l.transposed else "conv"
                 res = resolve_execution(
@@ -348,13 +375,23 @@ class ProgramSpec:
                     sharding=res.sharding))
         _obs.counter("program.builds").inc()
         return cls(model=cfg.name, role=role, batch=int(batch),
-                   z_dim=int(cfg.z_dim) if role == "generator" else None,
+                   z_dim=int(cfg.z_dim) if input_kind == "latent" else None,
                    channel_scale=float(cfg.channel_scale), dtype=dtype,
                    platform=jax.default_backend(),
                    requested_backend=policy.backend,
-                   layers=tuple(records), mesh=mesh)
+                   layers=tuple(records), mesh=mesh,
+                   input_kind=input_kind)
 
     # -- queries ------------------------------------------------------------
+    @property
+    def input_shape(self) -> tuple[int, ...]:
+        """The input per sample: ``(z_dim,)`` for a latent, else the
+        first layer's ``(*in_spatial, cin)``."""
+        if self.input_kind == "latent":
+            return (self.z_dim,)
+        first = self.layers[0]
+        return first.in_spatial + (first.cin,)
+
     def plan_keys(self) -> list[tuple[str, object]]:
         """(layer name, :class:`~repro.tune.PlanKey`) per layer — what
         the tuner's zoo entry points iterate instead of re-deriving
@@ -372,8 +409,9 @@ class ProgramSpec:
         mesh and the quantized payload are not (they change where/how
         the same function runs, not what it computes... up to the
         checked-in quantization tolerance)."""
-        return (self.model, self.role, self.z_dim, self.dtype, tuple(
-            le.geometry_signature() for le in self.layers))
+        return (self.model, self.role, self.input_kind, self.z_dim,
+                self.dtype, tuple(le.geometry_signature()
+                                  for le in self.layers))
 
     def summary(self) -> str:
         """One-line resolution summary (the repr-sized form of
@@ -396,6 +434,7 @@ class ProgramSpec:
             f"mesh={self.mesh[0]}x{self.mesh[1]}  "
         quant = "" if self.quantized_params is None else "quant=int8  "
         head = (f"program {self.model}/{self.role}  "
+                f"input={self.input_kind}  "
                 f"batch={self.batch}  dtype={self.dtype}  {quant}"
                 f"platform={self.platform}  {mesh}"
                 f"policy={self.requested_backend or 'heuristic'}  "
@@ -408,6 +447,7 @@ class ProgramSpec:
         doc = {
             "version": PROGRAM_FORMAT_VERSION,
             "model": self.model, "role": self.role, "batch": self.batch,
+            "input_kind": self.input_kind,
             "z_dim": self.z_dim, "channel_scale": self.channel_scale,
             "dtype": self.dtype, "platform": self.platform,
             "requested_backend": self.requested_backend,
@@ -444,6 +484,9 @@ class ProgramSpec:
             else "float32"
         quantized = doc.get("quantized_params") if version >= 3 else None
         z_dim = doc.get("z_dim")
+        # the input signature is optional: a file without it was written
+        # before image-input generators and holds the role's default
+        input_kind = doc.get("input_kind")
         return cls(model=str(doc["model"]), role=str(doc["role"]),
                    batch=int(doc["batch"]),
                    z_dim=None if z_dim is None else int(z_dim),
@@ -452,7 +495,9 @@ class ProgramSpec:
                    platform=str(doc.get("platform", "cpu")),
                    requested_backend=doc.get("requested_backend"),
                    layers=tuple(LayerExec.from_json(d) for d in layers),
-                   mesh=mesh, quantized_params=quantized)
+                   mesh=mesh, quantized_params=quantized,
+                   input_kind=None if input_kind is None
+                   else str(input_kind))
 
     def save(self, path) -> None:
         """Atomically write the spec's JSON document to ``path``."""

@@ -54,7 +54,11 @@ MODEL_TOLERANCES: dict[str, dict[str, dict]] = {
         "float16":  {"output_atol": 3e-5, "grad_rel": 3e-3},
         "int8":     {"output_atol": 5e-4, "grad_rel": None},
     },
-    "discogan": {  # observed: bf16 1.2e-6/1.7e-3, f16 2e-7/1.4e-3
+    # observed with the image-input generator (no z-projection,
+    # LeakyReLU encoder): bf16 6.9e-6/1.7e-3, f16 7.4e-7/3.7e-4, int8
+    # 1.9e-5 (the z-projected form read bf16 1.2e-6/1.7e-3, f16
+    # 2e-7/1.4e-3)
+    "discogan": {
         "bfloat16": {"output_atol": 1e-5, "grad_rel": 0.01},
         "float16":  {"output_atol": 2e-6, "grad_rel": 6e-3},
         "int8":     {"output_atol": 2e-5, "grad_rel": None},

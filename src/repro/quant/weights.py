@@ -156,12 +156,12 @@ def quantize_program(spec, params: dict):
     weight payload embedded — the exportable v3-program form.
 
     Validates that ``params`` covers every parameter the spec's layers
-    (plus the generator projection) read, so a wrong tree fails at
+    (plus a latent generator's projection) read, so a wrong tree fails at
     export, not on the serving box.  ``canonical_dtype`` runs on the
     spec's storage dtype as a belt-and-braces check."""
     canonical_dtype(spec.dtype)
     required = set()
-    if spec.role == "generator":
+    if spec.input_kind == "latent":
         required |= {"proj_w", "proj_b"}
     for le in spec.layers:
         required.add(le.w_param)

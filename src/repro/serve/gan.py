@@ -55,6 +55,7 @@ from repro.core.dataflow import DataflowPolicy
 from repro.models.gan import GanConfig
 from repro.program import Program, ProgramSpec
 from repro.program.spec import _UNSET as _MESH_UNSET
+from repro.serve.gan_engine import refuse_image_input
 
 __all__ = ["GanServer"]
 
@@ -81,6 +82,7 @@ class GanServer:
             # serving-time storage-precision override (canonicalized by
             # GanConfig; accumulation stays f32 — see repro.quant)
             cfg = dataclasses.replace(cfg, dtype=dtype)
+        refuse_image_input(cfg)
         self.cfg = cfg
         if g_params is None:
             # int8-deploy flow: a quantized program carries its own

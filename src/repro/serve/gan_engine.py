@@ -78,6 +78,16 @@ __all__ = ["GanEngine", "GanFuture", "ServerClosed", "DEFAULT_BUCKETS"]
 
 DEFAULT_BUCKETS = (1, 2, 4, 8)
 
+
+def refuse_image_input(cfg: GanConfig) -> None:
+    """Serving draws latents for a request of ``n`` samples; a generator
+    that takes an image (``cfg.input_kind``, DiscoGAN) needs requests
+    that carry one, which the engine does not take yet (ROADMAP 2.3)."""
+    if cfg.input_kind != "latent":
+        raise ValueError(
+            f"{cfg.name} is an image-input generator: serving feeds "
+            f"latents and takes no per-request images yet (ROADMAP 2.3)")
+
 # Occupancy is assigned/bucket in (0, 1] — latency buckets make no
 # sense for it (same bounds the synchronous server uses).
 _OCCUPANCY_BOUNDS = tuple(i / 10 for i in range(1, 11))
@@ -221,6 +231,7 @@ class GanEngine:
             # serving-time storage-precision override (canonicalized by
             # GanConfig; accumulation stays f32 — see repro.quant)
             cfg = dataclasses.replace(cfg, dtype=dtype)
+        refuse_image_input(cfg)
         if g_params is None:
             # int8-deploy flow: a quantized program carries its own
             # (dequantized-at-load) parameters

@@ -48,6 +48,14 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
     plan misses at build for an ``auto`` policy (never during the
     loop).
 
+    The model chooses the step.  A latent generator gets the
+    adversarial step above; an image-input generator
+    (``cfg.input_kind == "image"``, DiscoGAN) gets the cross-domain
+    step of :func:`_cross_domain_step`, whose state is
+    ``((g_ab, g_ba), (d_a, d_b))`` and whose batch is ``{"a", "b"}``,
+    two image batches of unpaired domains.  Both return the metrics
+    ``{"g_loss", "d_loss", "loss"}``.
+
     ``mesh`` (default: ``cfg.mesh``) builds **sharded** programs: the
     programs' forwards run under ``shard_map``, so the batch splits
     over the ``data`` axis and the weight cotangents are ``psum``-med
@@ -57,7 +65,8 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
     :func:`repro.sharding.rules.batch_sharding` (batch dim over
     ``data``), and exposes ``train_step.state_shardings`` — a
     ``(g, d)`` pair of replicated :func:`~repro.sharding.rules
-    .param_shardings` trees — for placing the initial state and for
+    .param_shardings` trees (``((g, g), (d, d))`` for the cross-domain
+    step) — for placing the initial state and for
     :class:`TrainLoop`'s checkpoint-restore ``state_shardings``.
     Degrades with the programs: too few local devices → a plain
     single-device step.
@@ -74,7 +83,6 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
     ``value_and_grad`` run under ``jax.named_scope("gan.d_update")`` and
     ``"gan.g_update"``, the SGD updates under ``"gan.sgd"``, and each
     layer inside them under the programs' ``layer.<name>`` scopes."""
-    from repro.models.gan import bce_with_logits
     from repro.program import Program
 
     d_lr = g_lr if d_lr is None else d_lr
@@ -82,34 +90,9 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
                            planner=planner, measure=measure, mesh=mesh)
     d_prog = Program.build(cfg, batch, "discriminator", policy=policy,
                            planner=planner, measure=measure, mesh=mesh)
-
-    def losses(g_params, d_params, z, real):
-        fake = g_prog.forward(g_params, z)
-        d_fake = d_prog.forward(d_params, fake)
-        d_real = d_prog.forward(d_params, real)
-        d_loss = bce_with_logits(d_real, 1.0) + \
-            bce_with_logits(d_fake, 0.0)
-        g_loss = bce_with_logits(d_fake, 1.0)
-        return g_loss, d_loss
-
-    @jax.jit
-    def train_step(state, batch):
-        g_params, d_params = state
-        z, real = batch["z"], batch["real"]
-        with jax.named_scope("gan.d_update"):
-            dl, d_grads = jax.value_and_grad(
-                lambda d: losses(g_params, d, z, real)[1])(d_params)
-        with jax.named_scope("gan.sgd"):
-            d_new = jax.tree.map(lambda p, g: p - d_lr * g, d_params,
-                                 d_grads)
-        with jax.named_scope("gan.g_update"):
-            gl, g_grads = jax.value_and_grad(
-                lambda g: losses(g, d_new, z, real)[0])(g_params)
-        with jax.named_scope("gan.sgd"):
-            g_new = jax.tree.map(lambda p, g: p - g_lr * g, g_params,
-                                 g_grads)
-        return (g_new, d_new), {"g_loss": gl, "d_loss": dl,
-                                "loss": gl + dl}
+    cross_domain = g_prog.spec.input_kind == "image"
+    make = _cross_domain_step if cross_domain else _adversarial_step
+    train_step = make(g_prog, d_prog, g_lr, d_lr)
 
     if g_prog.mesh is not None:
         from repro.models.gan import (discriminator_specs,
@@ -139,13 +122,106 @@ def make_gan_train_step(cfg, batch: int, *, g_lr: float = 2e-4,
                      for k, v in batch.items()}
             return inner_step(state, batch)
 
+        g_sh = _shardings(generator_specs(cfg))
+        d_sh = _shardings(discriminator_specs(cfg))
         train_step.mesh = mesh_obj
-        train_step.state_shardings = (_shardings(generator_specs(cfg)),
-                                      _shardings(discriminator_specs(cfg)))
+        train_step.state_shardings = ((g_sh, g_sh), (d_sh, d_sh)) \
+            if cross_domain else (g_sh, d_sh)
     else:
         train_step.mesh = None
         train_step.state_shardings = None
     return train_step, (g_prog, d_prog)
+
+
+def _sgd(params, grads, lr):
+    with jax.named_scope("gan.sgd"):
+        return jax.tree.map(lambda p, g: p - lr * g, params, grads)
+
+
+def _adversarial_step(g_prog, d_prog, g_lr, d_lr):
+    """The latent GAN's step: D on real and G(z), then G through the
+    updated D, both with the non-saturating BCE."""
+    from repro.models.gan import bce_with_logits
+
+    def losses(g_params, d_params, z, real):
+        fake = g_prog.forward(g_params, z)
+        d_fake = d_prog.forward(d_params, fake)
+        d_real = d_prog.forward(d_params, real)
+        d_loss = bce_with_logits(d_real, 1.0) + \
+            bce_with_logits(d_fake, 0.0)
+        g_loss = bce_with_logits(d_fake, 1.0)
+        return g_loss, d_loss
+
+    @jax.jit
+    def train_step(state, batch):
+        g_params, d_params = state
+        z, real = batch["z"], batch["real"]
+        with jax.named_scope("gan.d_update"):
+            dl, d_grads = jax.value_and_grad(
+                lambda d: losses(g_params, d, z, real)[1])(d_params)
+        d_new = _sgd(d_params, d_grads, d_lr)
+        with jax.named_scope("gan.g_update"):
+            gl, g_grads = jax.value_and_grad(
+                lambda g: losses(g, d_new, z, real)[0])(g_params)
+        g_new = _sgd(g_params, g_grads, g_lr)
+        return (g_new, d_new), {"g_loss": gl, "d_loss": dl,
+                                "loss": gl + dl}
+
+    return train_step
+
+
+def _cross_domain_step(g_prog, d_prog, g_lr, d_lr):
+    """DiscoGAN's step (Kim et al. 2017, Eq. 1-6) over unpaired domains
+    A and B.  One generator and one discriminator program serve both
+    directions and both domains, each with its own parameters: the
+    state is ``((g_ab, g_ba), (d_a, d_b))`` and a batch ``{"a", "b"}``.
+
+    D's update: ``L_D = L_D_A + L_D_B``, BCE on real and on the
+    translated fake in each domain.  Then G's update through the updated
+    D: ``L_G = L_GAN_B + L_CONST_A + L_GAN_A + L_CONST_B``, with L_GAN
+    the non-saturating BCE of ``D_B(x_AB)`` (``D_A(x_BA)``) against
+    real and L_CONST the MSE between ``x_ABA = G_BA(x_AB)`` and ``x_A``
+    (``x_BAB`` and ``x_B``).  The translations run under
+    ``gan.translate``, the reconstructions and their MSE under
+    ``gan.reconstruct``."""
+    import jax.numpy as jnp
+
+    from repro.models.gan import bce_with_logits as bce
+    gen, disc = g_prog.forward, d_prog.forward
+
+    def translate(g_ab, g_ba, a, b):
+        with jax.named_scope("gan.translate"):
+            return gen(g_ab, a), gen(g_ba, b)
+
+    def d_loss(ds, a, b, x_ab, x_ba):
+        d_a, d_b = ds
+        return (bce(disc(d_a, a), 1.0) + bce(disc(d_a, x_ba), 0.0)
+                + bce(disc(d_b, b), 1.0) + bce(disc(d_b, x_ab), 0.0))
+
+    def g_loss(gs, ds, a, b):
+        (g_ab, g_ba), (d_a, d_b) = gs, ds
+        x_ab, x_ba = translate(g_ab, g_ba, a, b)
+        with jax.named_scope("gan.reconstruct"):
+            const = (jnp.mean(jnp.square(gen(g_ba, x_ab) - a))
+                     + jnp.mean(jnp.square(gen(g_ab, x_ba) - b)))
+        return bce(disc(d_b, x_ab), 1.0) + bce(disc(d_a, x_ba), 1.0) \
+            + const
+
+    @jax.jit
+    def train_step(state, batch):
+        gs, ds = state
+        a, b = batch["a"], batch["b"]
+        with jax.named_scope("gan.d_update"):
+            fake = translate(*gs, a, b)
+            dl, d_grads = jax.value_and_grad(d_loss)(ds, a, b, *fake)
+        d_new = _sgd(ds, d_grads, d_lr)
+        with jax.named_scope("gan.g_update"):
+            gl, g_grads = jax.value_and_grad(g_loss)(gs, d_new, a, b)
+        g_new = _sgd(gs, g_grads, g_lr)
+        return (g_new, d_new), {"g_loss": gl, "d_loss": dl,
+                                "loss": gl + dl}
+
+    return train_step
 
 
 class InjectedFailure(RuntimeError):

@@ -148,7 +148,7 @@ def tune_model_zoo(models: Sequence[str], planner: Planner, *,
                "layer_tuned_us_sum": tuned_us if complete else None}
         if end_to_end:
             g_params, _ = init_gan(cfg, jax.random.PRNGKey(0))
-            z = jnp.zeros((batch, cfg.z_dim), jnp.float32)
+            z = jnp.zeros((batch, *cfg.input_shape), jnp.float32)
             # "auto" dispatch consults the *process-wide* planner; point
             # it at the one we just tuned for the timed run
             from repro.tune import get_planner, set_planner

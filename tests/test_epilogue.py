@@ -165,19 +165,21 @@ def _top_level_prims(fn, *args) -> list[str]:
 def test_no_out_of_kernel_epilogue_ops(name):
     """On the fused kernel path every conv layer traces to a single
     custom-VJP call: no top-level ``add`` (bias) and no top-level
-    tanh/max/select_n (activations) besides the generator's z-projection
-    MLP, for every Table-I model."""
+    tanh/max/select_n (activations) besides a latent generator's
+    z-projection MLP, for every Table-I model (DiscoGAN's image-input
+    generator has no projection)."""
     cfg = GanConfig(name=name, channel_scale=0.03125,
                     backend="pallas-interpret")
     g, d = init_gan(cfg, jax.random.PRNGKey(0))
     g_layers, d_layers = cfg.layers
-    z = jnp.zeros((1, cfg.z_dim))
+    z = jnp.zeros((1, *cfg.input_shape))
+    proj = int(cfg.input_kind == "latent")
 
     prims = _top_level_prims(lambda g, z: generator_apply(g, z, cfg), g, z)
     activationish = {"tanh", "max", "select_n", "logistic"}
     assert not activationish & set(prims), prims
-    assert prims.count("add") == 1, prims            # the projection bias
-    assert prims.count("custom_jvp_call") == 1       # the projection relu
+    assert prims.count("add") == proj, prims         # the projection bias
+    assert prims.count("custom_jvp_call") == proj    # the projection relu
     assert prims.count(CUSTOM_VJP_CALL) == len(g_layers)
 
     img_sp = tuple(d_layers[0].in_spatial)
@@ -197,7 +199,7 @@ def test_model_fused_matches_unfused(name):
     cfg = GanConfig(name=name, channel_scale=0.0625, backend="polyphase")
     g, d = init_gan(cfg, jax.random.PRNGKey(0))
     g_layers, d_layers = cfg.layers
-    z = jax.random.normal(jax.random.PRNGKey(1), (2, cfg.z_dim))
+    z = jax.random.normal(jax.random.PRNGKey(1), (2, *cfg.input_shape))
 
     from repro.core.dataflow import conv as df_conv
     from repro.core.dataflow import tconv as df_tconv
@@ -205,10 +207,12 @@ def test_model_fused_matches_unfused(name):
     def unfused_generator(params):
         policy = cfg.policy
         first = g_layers[0]
-        x = z @ params["proj_w"] + params["proj_b"]
-        x = x.reshape((z.shape[0],) + tuple(first.in_spatial)
-                      + (first.cin,))
-        x = jax.nn.relu(x)
+        x = z
+        if cfg.input_kind == "latent":
+            x = z @ params["proj_w"] + params["proj_b"]
+            x = x.reshape((z.shape[0],) + tuple(first.in_spatial)
+                          + (first.cin,))
+            x = jax.nn.relu(x)
         for i, (l, ep) in enumerate(zip(g_layers,
                                         generator_epilogues(g_layers))):
             op = df_tconv if l.transposed else df_conv

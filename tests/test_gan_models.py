@@ -15,7 +15,7 @@ from repro.models.gan import (GanConfig, gan_losses, generator_apply,
 def test_generator_shapes_and_losses(name):
     cfg = GanConfig(name=name, channel_scale=0.0625)
     g, d = init_gan(cfg, jax.random.PRNGKey(0))
-    z = jax.random.normal(jax.random.PRNGKey(1), (2, cfg.z_dim))
+    z = jax.random.normal(jax.random.PRNGKey(1), (2, *cfg.input_shape))
     img = generator_apply(g, z, cfg)
     nd = len(cfg.layers[0][-1].kernel)
     assert img.ndim == nd + 2 and img.shape[0] == 2

@@ -48,9 +48,12 @@ def _legacy_generator_apply(params, z, cfg, policy):
     config → policy → epilogues at every call site."""
     g_layers, _ = cfg.layers
     first = g_layers[0]
-    x = z @ params["proj_w"] + params["proj_b"]
-    x = x.reshape((z.shape[0],) + tuple(first.in_spatial) + (first.cin,))
-    x = jax.nn.relu(x)
+    x = z
+    if cfg.input_kind == "latent":
+        x = z @ params["proj_w"] + params["proj_b"]
+        x = x.reshape((z.shape[0],) + tuple(first.in_spatial)
+                      + (first.cin,))
+        x = jax.nn.relu(x)
     for i, (l, ep) in enumerate(zip(g_layers,
                                     generator_epilogues(g_layers))):
         op = df_tconv if l.transposed else df_conv
@@ -80,7 +83,7 @@ def test_program_matches_legacy_every_model(name, backend):
     generator_apply threading for every Table-I model."""
     cfg = GanConfig(name=name, channel_scale=0.0625, backend=backend)
     g, _ = init_gan(cfg, jax.random.PRNGKey(0))
-    z = jax.random.normal(jax.random.PRNGKey(1), (2, cfg.z_dim))
+    z = jax.random.normal(jax.random.PRNGKey(1), (2, *cfg.input_shape))
     prog = Program.build(cfg, 2, "generator")
     legacy = _legacy_generator_apply(g, z, cfg, cfg.policy)
     np.testing.assert_array_equal(np.asarray(prog.apply(g, z)),

@@ -151,7 +151,7 @@ def _grad_tree_rel(a: dict, b: dict) -> float:
 def _f32_reference(name, backend="polyphase"):
     cfg = GanConfig(name, channel_scale=_SCALE, backend=backend)
     g, _ = init_gan(cfg, jax.random.PRNGKey(0))
-    z = jax.random.normal(jax.random.PRNGKey(1), (2, cfg.z_dim),
+    z = jax.random.normal(jax.random.PRNGKey(1), (2, *cfg.input_shape),
                           jnp.float32)
     prog = Program.build(cfg, 2, "generator")
     y = prog.forward(g, z)

@@ -223,7 +223,8 @@ def test_all_table1_models_sharded_parity():
         for name in sorted(GAN_MODELS):
             cfg = GanConfig(name=name, channel_scale=0.0625)
             g, d = init_gan(cfg, jax.random.PRNGKey(0))
-            z = jax.random.normal(jax.random.PRNGKey(1), (8, cfg.z_dim))
+            z = jax.random.normal(jax.random.PRNGKey(1),
+                                  (8, *cfg.input_shape))
             plain = Program.build(cfg, 8, mesh=None)
             sharded = Program.build(cfg, 8, mesh=(4, 2),
                                     cout_shard_min_bytes=0)
