@@ -31,7 +31,10 @@ repo.  It owns three things:
    forward and backward passes.  The pure-JAX backends keep XLA's
    native autodiff, which is already fused (and, for polyphase,
    already zero-skipping — the backward of a phase conv is a phase
-   conv, that of the packed form two stride-1 convs).
+   conv, that of the packed form two stride-1 convs).  One exception
+   on polyphase: a strided conv with a lane-sparse Cin carries a
+   ``jax.custom_jvp`` whose input gradient is the packed tconv
+   (``_conv_packed_dx``); it too differentiates to any order.
 
 4. **Fused epilogue** — an :class:`Epilogue` (bias add + activation)
    is a first-class argument of :func:`tconv` / :func:`conv`.  On the
@@ -60,6 +63,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.custom_derivatives import SymbolicZero
 
 from repro import obs as _obs
 from repro.core.scheduler import PhaseSchedule, make_schedule
@@ -404,24 +408,91 @@ def _conv_dense(x, w, strides, paddings):
 _LANES = 128
 
 
+def _packs_phases(channels: int, strides: Sequence[int]) -> bool:
+    """Whether a strided transposed conv with ``channels`` output
+    channels takes the packed form.  The phase convs read all of x once
+    per phase, and with few output channels each makes few MACs per
+    element of x it reads, so they are bound by those reads; the packed
+    form reads x once.  Taken for a lane-sparse output (even its phases
+    packed into channels fill no lane tile) with 8 phases or more: a 3-D
+    stride-2 layer.  A stride-1 layer has one phase and nothing to save.
+    A 2-D stride-2 layer (4 phases) gains too, but keeps the phase convs
+    until the benchmark's least-bytes count can measure it (PERF.md,
+    Open question 9).  A conv's input gradient is the transposed conv
+    whose output channels are the conv's input channels, so the conv
+    asks about its Cin."""
+    phases = int(np.prod(strides))
+    return phases >= 8 and channels * phases < _LANES
+
+
 def _tconv_polyphase(x, w, strides, paddings):
     nd = x.ndim - 2
     u = compile_uops(x.shape[1:1 + nd], w.shape[:nd], tuple(strides),
                      tuple(paddings))
-    # The phase convs read all of x once per phase, and with few output
-    # channels each makes few MACs per element of x it reads, so they
-    # are bound by those reads; the packed form reads x once.  Taken for
-    # a lane-sparse output (even its phases packed into channels fill no
-    # lane tile) with 8 phases or more: a 3-D stride-2 output layer.  A
-    # stride-1 layer has one phase and nothing to save.  A 2-D stride-2
-    # output layer (4 phases) gains too, but keeps the phase convs until
-    # the benchmark's least-bytes count can measure it (PERF.md, Open
-    # question 9).
-    phases = int(np.prod(strides))
-    if phases >= 8 and w.shape[-1] * phases < _LANES:
+    if _packs_phases(w.shape[-1], strides):
         _obs.counter("dataflow.tconv.taps").inc()
         return tconv_taps(x, w, strides, paddings, schedule=u.schedule)
     return tconv_ganax(x, w, strides, paddings, schedule=u.schedule)
+
+
+def _crop_adjoint(dx_full, in_spatial, paddings):
+    """The input cotangent of a conv from its uncropped adjoint: a conv
+    with padding p reads input positions [-p, s·(Q-1)+K-1-p], so its
+    adjoint is the tconv with padding 0 shifted by p, cropped to [0, I)
+    with zero cotangent past the stride tail."""
+    slc = [slice(None)]
+    pad = [(0, 0)]
+    for d, (i_d, p) in enumerate(zip(in_spatial, paddings)):
+        slc.append(slice(p, p + i_d))
+        pad.append((0, max(0, i_d - (dx_full.shape[1 + d] - p))))
+    slc.append(slice(None))
+    pad.append((0, 0))
+    return jnp.pad(dx_full[tuple(slc)], pad)
+
+
+def _conv_polyphase(x, w, strides, paddings):
+    if _packs_phases(w.shape[-2], strides):
+        _obs.counter("dataflow.conv.dx_taps").inc()
+        return _conv_packed_dx(tuple(strides), tuple(paddings), x, w)
+    return _conv_dense(x, w, strides, paddings)
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(0, 1))
+def _conv_packed_dx(strides, paddings, x, w):
+    """A strided conv whose input gradient is the packed tconv
+    (:func:`repro.core.tconv.tconv_taps`): one stride-1 conv into
+    ``prod(s)·Cin`` phase channels and an interleave, where autodiff of
+    the strided conv would zero-insert the cotangent and write ``Cin``
+    lane-sparse channels.  The forward pass and the weight gradient are
+    the plain conv's.  A JVP, not a VJP: its x-tangent map is the
+    transpose of the packed input gradient, so reverse mode transposes it
+    back into the packed form, and derivatives of any order, forward mode
+    included, stay native."""
+    return _conv_dense(x, w, strides, paddings)
+
+
+@functools.partial(_conv_packed_dx.defjvp, symbolic_zeros=True)
+def _conv_packed_dx_jvp(strides, paddings, primals, tangents):
+    x, w = primals
+    x_dot, w_dot = tangents
+    y = _conv_dense(x, w, strides, paddings)
+    nd = x.ndim - 2
+    terms = []
+    if not isinstance(x_dot, SymbolicZero):
+        u = compile_uops(y.shape[1:1 + nd], w.shape[:nd], strides, (0,) * nd)
+
+        def packed_dx(g):
+            dx_full = tconv_taps(g, _swap_io(w), strides, (0,) * nd,
+                                 schedule=u.schedule)
+            return _crop_adjoint(dx_full, x.shape[1:1 + nd], paddings)
+
+        conv_x, = jax.linear_transpose(
+            packed_dx, jax.ShapeDtypeStruct(y.shape, y.dtype))(x_dot)
+        terms.append(conv_x)
+    if not isinstance(w_dot, SymbolicZero):
+        terms.append(_conv_dense(x, w_dot, strides, paddings))
+    # JAX calls no rule when every tangent is zero, so terms is not empty
+    return y, functools.reduce(jnp.add, terms)
 
 
 def _pallas(interpret: bool, transposed: bool):
@@ -436,7 +507,7 @@ def _pallas(interpret: bool, transposed: bool):
 register_backend(Backend(
     name="zero-insert", tconv=tconv_zero_insert, conv=_conv_dense))
 register_backend(Backend(
-    name="polyphase", tconv=_tconv_polyphase, conv=_conv_dense))
+    name="polyphase", tconv=_tconv_polyphase, conv=_conv_polyphase))
 register_backend(Backend(
     name="pallas-interpret", tconv=_pallas(True, True),
     conv=_pallas(True, False), supports=pallas_kernel_supported))
@@ -738,22 +809,11 @@ def _conv_fwd(backend, strides, paddings, blocks, x, w):
 
 def _conv_dx(backend, strides, paddings, x, w, g):
     """Input-cotangent of ``y = conv(x, w)``: a transposed conv (the
-    multi-phase MIMD path) — but the *uncropped* one: conv with padding
-    p reads input positions [-p, s·(Q-1)+K-1-p], so the adjoint is tconv
-    with padding 0 shifted by p, cropped to [0, I) with zero cotangent
-    past the stride tail."""
+    multi-phase MIMD path), uncropped and then cropped by
+    :func:`_crop_adjoint`."""
     nd = x.ndim - 2
     dx_full = _run(backend, True, g, _swap_io(w), strides, (0,) * nd)
-    slc = [slice(None)]
-    pad = [(0, 0)]
-    for d in range(nd):
-        i_d = x.shape[1 + d]
-        avail = dx_full.shape[1 + d] - paddings[d]
-        slc.append(slice(paddings[d], paddings[d] + i_d))
-        pad.append((0, max(0, i_d - avail)))
-    slc.append(slice(None))
-    pad.append((0, 0))
-    return jnp.pad(dx_full[tuple(slc)], pad)
+    return _crop_adjoint(dx_full, x.shape[1:1 + nd], paddings)
 
 
 def _conv_bwd(backend, strides, paddings, blocks, res, g):

@@ -24,7 +24,8 @@ Three executable forms are provided for N-D transposed convolution
   reads x once where the phase convs read it once per phase, which is
   what bounds a phase conv with few output channels.  The polyphase
   backend (``core/dataflow.py``) takes it for a lane-sparse output
-  layer with 8 phases or more (3dgan g4).
+  layer with 8 phases or more (3dgan g4), and for the input gradient
+  of a conv with such an input (3dgan d1).
 
 All produce bit-comparable results (up to dtype accumulation order) and
 match ``jax.lax.conv_transpose``.

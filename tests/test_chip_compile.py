@@ -13,8 +13,8 @@ only once one of its tests has started.
   compile; the three known refusals are strict xfails, so the kernel
   fix that lifts one flips its case.
 * The default resolution (``DataflowPolicy()``, polyphase) compiles
-  every layer of dcgan, and 3dgan g4 in the packed form, forward and
-  backward.
+  every layer of dcgan, 3dgan g4 in the packed form and 3dgan d1 with
+  its input gradient in the packed form, forward and backward.
 """
 
 import re
@@ -111,7 +111,7 @@ def _conv_output_features(hlo: str) -> list[int]:
 
 
 _DEFAULT_LAYERS = [("dcgan", l.name) for role in GAN_MODELS["dcgan"]
-                   for l in role] + [("3dgan", "g4")]
+                   for l in role] + [("3dgan", "g4"), ("3dgan", "d1")]
 
 
 @pytest.mark.parametrize(
@@ -120,8 +120,9 @@ _DEFAULT_LAYERS = [("dcgan", l.name) for role in GAN_MODELS["dcgan"]
 def test_default_resolution_compiles_for_v5e(one_chip, model, name):
     """``DataflowPolicy()`` resolves to a backend the chip's compiler
     accepts, for the forward pass and both cotangents.  3dgan g4 (Cout
-    1) runs in the packed form: no convolution of its module, forward or
-    backward, leaves the MXU a single output column."""
+    1) runs in the packed form, and so does d1's (Cin 1) input gradient:
+    no convolution of their modules, forward or backward, leaves the MXU
+    a single output column or convolves a zero-inserted input."""
     layer = _layer(model, name)
     f = _forward(layer, DataflowPolicy())
 
@@ -135,3 +136,4 @@ def test_default_resolution_compiles_for_v5e(one_chip, model, name):
     if model == "3dgan":
         feats = _conv_output_features(hlo)
         assert feats and 1 not in feats, feats
+        assert "lhs_dilate" not in hlo

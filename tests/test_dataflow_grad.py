@@ -14,8 +14,9 @@ import numpy as np
 import pytest
 
 from repro import obs as _obs
-from repro.core.dataflow import (DataflowPolicy, SecondOrderNotImplemented,
-                                 compile_uops, conv, tconv,
+from repro.core.dataflow import (DataflowPolicy, Epilogue,
+                                 SecondOrderNotImplemented, compile_uops,
+                                 conv, tconv,
                                  uop_cache_clear, uop_cache_info)
 from repro.core.tconv import tconv_taps, tconv_zero_insert
 from repro.kernels.ref import conv_ref
@@ -160,10 +161,6 @@ TAPS_CASES = [
 ]
 
 
-def _taps_count() -> int:
-    return _obs.snapshot()["counters"].get("dataflow.tconv.taps", 0)
-
-
 @pytest.mark.parametrize("xs,ws,s,p", TAPS_CASES)
 def test_tconv_taps_grad_parity(xs, ws, s, p):
     """dx, dw and db of the packed form (native autodiff through the
@@ -187,36 +184,105 @@ def test_tconv_taps_grad_parity(xs, ws, s, p):
                                    atol=1e-4, rtol=1e-4, err_msg=name)
 
 
-# The zoo's tconvs that take the packed form: a lane-sparse output layer
-# with 8 phases or more.  Every other layer keeps the phase convs: the
-# strided ones with Cout >= 64, the 2-D output layers (4 phases) and the
-# stride-1 ones (one phase, so no x read per phase to save).
-TAPS_LAYERS = {("3dgan", "g4")}
+# A strided conv with a lane-sparse input, whose input gradient is the
+# packed tconv: Cin 1 and 2 at stride 2^3, paddings 1 and 0, and odd
+# sizes that leave a stride tail the conv never reads.
+DX_TAPS_CASES = [
+    ((2, 6, 6, 6, 1), (4, 4, 4, 1, 3), (2, 2, 2), (1, 1, 1)),
+    ((1, 6, 4, 6, 2), (4, 4, 4, 2, 5), (2, 2, 2), (0, 0, 0)),
+    ((1, 7, 9, 5, 1), (4, 4, 4, 1, 4), (2, 2, 2), (1, 1, 1)),
+    ((1, 9, 7, 8, 2), (3, 3, 3, 2, 3), (2, 2, 2), (0, 0, 0)),
+]
 
 
-def test_tconv_taps_routing_pins_zoo():
-    """The rule is geometric: across every zoo model's tconv layers at
-    published widths, ``dataflow.tconv.taps`` counts exactly the layers
+def _count(name: str) -> int:
+    return _obs.snapshot()["counters"].get(name, 0)
+
+
+@pytest.mark.parametrize("xs,ws,s,p", DX_TAPS_CASES)
+def test_conv_dx_taps_grad_parity(xs, ws, s, p):
+    """dx, dw and db of a routed conv (LeakyReLU 0.2 + bias epilogue) on
+    the polyphase backend match the zero-insert backend's native
+    autodiff, and the conv takes the packed input gradient."""
+    rng = np.random.default_rng(hash((xs, ws, s, p)) % 2**31)
+    x = jnp.asarray(rng.normal(size=xs), jnp.float32)
+    w = jnp.asarray(rng.normal(size=ws), jnp.float32)
+    b = jnp.asarray(rng.normal(size=ws[-1:]), jnp.float32)
+    ep = Epilogue(bias=True, activation="leaky_relu", leaky_slope=0.2)
+    cot = jnp.asarray(rng.normal(size=conv_ref(x, w, s, p).shape),
+                      jnp.float32)
+
+    def grads(backend):
+        policy = DataflowPolicy(backend=backend)
+        return jax.grad(lambda x, w, b: jnp.sum(conv(
+            x, w, s, p, policy=policy, bias=b, epilogue=ep) * cot),
+            argnums=(0, 1, 2))(x, w, b)
+
+    before = _count("dataflow.conv.dx_taps")
+    got = grads("polyphase")
+    assert _count("dataflow.conv.dx_taps") == before + 1
+    ref = grads("zero-insert")
+    for g, r, name in zip(got, ref, ("dx", "dw", "db")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+def test_conv_dx_taps_ops_named_for_the_layer():
+    """The packed input gradient's ops keep the layer's scope and a
+    ``transpose(`` in their path, which is how the benchmark's per-layer
+    split (``bench/layers.py``) credits an op to the layer's backward."""
+    import re
+
+    x = jnp.ones((2, 6, 6, 6, 1))
+    w = jnp.ones((4, 4, 4, 1, 3))
+
+    def loss(x):
+        with jax.named_scope("layer.d1"):
+            return jnp.sum(conv(x, w, (2, 2, 2), (1, 1, 1)) ** 2)
+
+    hlo = jax.jit(jax.grad(loss)).lower(x).compile().as_text()
+    taps = [p for p in re.findall(r'op_name="([^"]*)"', hlo)
+            if "tconv.taps" in p or "tconv.interleave" in p]
+    assert taps
+    for path in taps:
+        assert "layer.d1" in path and "transpose(" in path, path
+
+
+# The zoo's layers that take a packed form on the polyphase backend: a
+# tconv with a lane-sparse output, and a conv with a lane-sparse input
+# (its input gradient is that tconv), both with 8 phases or more.  Every
+# other layer keeps the phase convs or autodiff of the plain conv: the
+# strided ones with 64 channels or more there, the 2-D image layers (4
+# phases) and the stride-1 ones (one phase, so no x read per phase to
+# save).
+@pytest.mark.parametrize("transposed,counter,pinned", [
+    (True, "dataflow.tconv.taps", {("3dgan", "g4")}),
+    (False, "dataflow.conv.dx_taps", {("3dgan", "d1")}),
+], ids=["tconv", "conv"])
+def test_tconv_taps_routing_pins_zoo(transposed, counter, pinned):
+    """The rule is geometric: across every zoo model's layers of one
+    kind at published widths, the counter counts exactly the layers
     pinned above, and the default resolution routes them so."""
     from repro.configs.gans import GAN_MODELS
 
+    op = tconv if transposed else conv
     taken = set()
     for model, (g_layers, d_layers) in GAN_MODELS.items():
         for layer in g_layers + d_layers:
-            if not layer.transposed:
+            if layer.transposed != transposed:
                 continue
             x = jax.ShapeDtypeStruct((1, *layer.in_spatial, layer.cin),
                                      jnp.float32)
             w = jax.ShapeDtypeStruct((*layer.kernel, layer.cin, layer.cout),
                                      jnp.float32)
-            before = _taps_count()
-            jax.eval_shape(lambda x, w: tconv(x, w, layer.strides,
-                                              layer.paddings), x, w)
-            count = _taps_count() - before
+            before = _count(counter)
+            jax.eval_shape(lambda x, w: op(x, w, layer.strides,
+                                           layer.paddings), x, w)
+            count = _count(counter) - before
             assert count in (0, 1), (model, layer.name, count)
             if count:
                 taken.add((model, layer.name))
-    assert taken == TAPS_LAYERS
+    assert taken == pinned
 
 
 def test_3dgan_layers_pallas_forward_and_vjp_parity():
@@ -281,23 +347,37 @@ def test_second_order_autodiff_raises_clearly(op):
         jax.grad(lambda x: jnp.sum(jax.grad(loss)(x)))(x)
 
 
-def test_second_order_supported_on_pure_jax_backends():
-    """Higher-order autodiff keeps working where XLA natively provides
-    it, and matches across the two zero-free formulations."""
-    x = jnp.ones((1, 3, 3, 2)) * 0.5
-    w = jnp.ones((2, 2, 2, 2)) * 0.25
+@pytest.mark.parametrize("op,xs,ws,s,p", [
+    (tconv, (1, 3, 3, 2), (2, 2, 2, 2), (2, 2), (0, 0)),
+    # a routed conv: its input gradient is the packed tconv
+    (conv, (1, 6, 5, 6, 1), (4, 4, 4, 1, 2), (2, 2, 2), (1, 1, 1)),
+], ids=["tconv", "conv-dx-taps"])
+def test_second_order_supported_on_pure_jax_backends(op, xs, ws, s, p):
+    """Higher-order autodiff, and forward mode, keep working where XLA
+    natively provides them, and match across the two zero-free
+    formulations."""
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=xs), jnp.float32) * 0.5
+    w = jnp.asarray(rng.normal(size=ws), jnp.float32) * 0.25
 
-    def loss(policy):
-        def f(x):
-            return jnp.sum(tconv(x, w, (2, 2), (0, 0), policy=policy) ** 3)
-        return f
+    def f(backend):
+        policy = DataflowPolicy(backend=backend)
+        return lambda x, w: op(x, w, s, p, policy=policy)
 
-    g2 = {b: jax.grad(lambda x: jnp.sum(jax.grad(loss(
-        DataflowPolicy(backend=b)))(x)))(x)
-        for b in ("polyphase", "zero-insert")}
+    def loss(backend):
+        return lambda x: jnp.sum(f(backend)(x, w) ** 3)
+
+    g2 = {b: jax.grad(lambda x: jnp.sum(jax.grad(loss(b))(x)))(x)
+          for b in ("polyphase", "zero-insert")}
     np.testing.assert_allclose(np.asarray(g2["polyphase"]),
                                np.asarray(g2["zero-insert"]),
                                atol=1e-4, rtol=1e-4)
+    tangents = (x * 0.3, w * 0.7)
+    jvps = {b: jax.jvp(f(b), (x, w), tangents)
+            for b in ("polyphase", "zero-insert")}
+    for a, r in zip(jvps["polyphase"], jvps["zero-insert"]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)
 
 
 def test_uop_cache_hit_on_repeated_geometry():
